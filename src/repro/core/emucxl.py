@@ -6,9 +6,9 @@ The paper's library hands out virtual addresses backed by `kmalloc_node` on NUMA
   node 0 (LOCAL)  -> ``memory_kind="device"``      (TPU HBM; CPU default space in tests)
   node 1 (REMOTE) -> ``memory_kind="pinned_host"`` (host DRAM behind PCIe, the CXL.mem proxy)
 
-On runtimes whose devices expose neither kind (older jax on CPU), both tiers fall back
-to the device's default memory — tier placement stays fully modeled in the registry and
-the cost model, which is what the tests and benchmarks consume.
+A CPU device that lacks these kinds puts both tiers in its default memory — tier
+placement stays fully modeled in the registry and the cost model. An accelerator that
+lacks them is refused at ``init``.
 
 Beyond the paper (CXL 3.0 direction): one ``EmuCXL`` instance can emulate **N hosts**
 sharing one remote pool through a switch fabric (``core/fabric.py``). Allocations carry
@@ -23,7 +23,7 @@ API; tensor views are layered on top for framework use. Every allocation carries
 (address, size, node, host, port) in a registry backing ``is_local / get_numa_node /
 get_size / stats``, exactly like the paper's user-space metadata structure.
 
-Differences from the paper, per DESIGN.md §2: accesses are DMA'd slices rather than
+Differences from the paper: accesses are DMA'd slices rather than
 cache-line loads (TPU cores cannot load from host memory), and ``memmove`` is identical to
 ``memcpy`` because functional arrays never alias.
 """
@@ -50,7 +50,7 @@ from repro.core.coherence import (
     total_stats,
 )
 from repro.core.fabric import Fabric, Transfer
-from repro.core.hw import V5E, HardwareModel
+from repro.core.hw import HARDWARE_MODELS, V5E, HardwareModel
 from repro.core.race import RACE_MODES
 from repro.core.policy import PlacementPolicy, StaticPlacement
 from repro.core.pool import PoolCapacityError, PoolQuotaError, SharedPool
@@ -116,17 +116,21 @@ class QuotaExceeded(EmuCXLError):
 
 
 def _resolve_memory_kinds(device) -> Dict[int, Optional[str]]:
-    """Map tiers to memory kinds the runtime actually supports."""
-    try:
-        kinds = {m.kind for m in device.addressable_memories()}
-    except Exception:
-        kinds = set()
-    if _PREFERRED_KINDS[LOCAL_MEMORY] in kinds and _PREFERRED_KINDS[REMOTE_MEMORY] in kinds:
+    """Map tiers to the memory kinds `device` exposes.
+
+    An accelerator must expose both preferred kinds: placing the remote tier in
+    device memory would hide the tier the library exists to emulate. Only a CPU
+    device without them collapses both tiers onto its default memory (placement
+    then stays modeled in the registry and the cost model)."""
+    kinds = {m.kind for m in device.addressable_memories()}
+    if set(_PREFERRED_KINDS.values()) <= kinds:
         return dict(_PREFERRED_KINDS)
-    try:
-        default = device.default_memory().kind
-    except Exception:
-        default = None
+    if device.platform != "cpu":
+        raise EmuCXLError(
+            f"{device.device_kind} exposes memory kinds {sorted(kinds)}; emucxl "
+            f"needs {sorted(_PREFERRED_KINDS.values())}"
+        )
+    default = device.default_memory().kind
     return {LOCAL_MEMORY: default, REMOTE_MEMORY: default}
 
 
@@ -249,6 +253,14 @@ class EmuCXL:
                     f"fabric has {fabric.num_hosts} hosts, emucxl needs {num_hosts}"
                 )
             self._device = device if device is not None else jax.devices()[0]
+            if (self._device.platform != "cpu"
+                    and HARDWARE_MODELS.get(self._device.device_kind) != self.hw):
+                # A CPU device runs the modeled target as is; a chip must be the
+                # one whose constants price the tiers.
+                raise EmuCXLError(
+                    f"{self._device.device_kind!r} is not modeled by {self.hw.name}; "
+                    f"add its HardwareModel to repro.core.hw.HARDWARE_MODELS"
+                )
             self._memory_kinds = _resolve_memory_kinds(self._device)
             self.num_hosts = num_hosts
             self.fabric = fabric
@@ -427,7 +439,7 @@ class EmuCXL:
             new_addr = self.alloc(size, rec.node, rec.host)
             new_rec = self._allocs[new_addr]
             n = min(size, rec.size)
-            new_rec.data = new_rec.data.at[:n].set(rec.data[:n])
+            self._write_bytes(new_rec, 0, self._read_bytes(rec, 0, n))
             if n > 0:
                 self._run_plan(self._plan_copy(rec, new_rec, n))
             self.free(rec.address)
@@ -812,6 +824,18 @@ class EmuCXL:
         return elapsed
 
     # ------------------------------------------------------------------ data movement
+    # A TPU runs no computation whose input is a ``pinned_host`` buffer: even an
+    # eager slice of one halts the core. So the bytes of an allocation cross
+    # between its tier and the host whole, by transfer, and are sliced and
+    # patched in host memory; the patched buffer is placed back on its tier.
+    def _read_bytes(self, store: Allocation, offset: int, n: int) -> np.ndarray:
+        return np.asarray(store.data)[offset : offset + n]
+
+    def _write_bytes(self, store: Allocation, offset: int, payload) -> None:
+        buf = np.array(store.data)
+        buf[offset : offset + len(payload)] = payload
+        store.data = jax.device_put(buf, self._sharding_for(store.node))
+
     def read(self, address: Union[int, Allocation], offset: int, buf_size: int) -> np.ndarray:
         """``emucxl_read``: DMA `buf_size` bytes at `offset` out of the allocation."""
         with self._lock:
@@ -819,8 +843,7 @@ class EmuCXL:
             plan = self._plan_dma(rec, offset, buf_size, write=False)
             self._touch(rec)
             self._run_plan(plan)
-            store = self._storage_rec(rec)
-            return np.asarray(store.data[offset : offset + buf_size])
+            return self._read_bytes(self._storage_rec(rec), offset, buf_size)
 
     def write(self, buf: np.ndarray, offset: int, address: Union[int, Allocation],
               buf_size: Optional[int] = None) -> bool:
@@ -831,8 +854,7 @@ class EmuCXL:
             n = buf_size if buf_size is not None else flat.size
             self._validate_payload(flat, n)
             plan = self._plan_dma(rec, offset, n, write=True)
-            store = self._storage_rec(rec)
-            store.data = store.data.at[offset : offset + n].set(flat[:n])
+            self._write_bytes(self._storage_rec(rec), offset, flat[:n])
             self._touch(rec)
             self._run_plan(plan)
             return True
@@ -842,9 +864,8 @@ class EmuCXL:
         with self._lock:
             rec = self._resolve(address)
             plan = self._plan_dma(rec, 0, size, write=True)
-            byte = np.uint8(value & 0xFF)
-            store = self._storage_rec(rec)
-            store.data = store.data.at[:size].set(byte)
+            self._write_bytes(self._storage_rec(rec), 0,
+                              np.full(size, value & 0xFF, np.uint8))
             self._touch(rec)
             self._run_plan(plan)
             return rec.address
@@ -866,11 +887,8 @@ class EmuCXL:
                 if journal is not None:
                     journal.rollback()
                 raise
-            sstore, dstore = self._storage_rec(srec), self._storage_rec(drec)
-            chunk = sstore.data[:size]
-            if dstore.node != sstore.node:
-                chunk = jax.device_put(chunk, self._sharding_for(dstore.node))
-            dstore.data = dstore.data.at[:size].set(chunk)
+            self._write_bytes(self._storage_rec(drec), 0,
+                              self._read_bytes(self._storage_rec(srec), 0, size))
             self._touch(drec)
             self._touch(srec)
             self._run_plan(plan)

@@ -53,6 +53,10 @@ class HardwareModel:
 
 V5E = HardwareModel()
 
+# The model of each chip this repo can run on, keyed by the device_kind JAX reports.
+# Peaks from Google Cloud's "TPU v5e" documentation.
+HARDWARE_MODELS = {"TPU v5 lite": V5E}
+
 # Chips per pod slice used throughout the launch configs.
 SINGLE_POD_CHIPS = 256
 MULTI_POD_CHIPS = 512

@@ -8,15 +8,15 @@ Everything here expresses the paper's local/remote split in XLA terms:
     makes offloaded AdamW viable (double-buffered moment fetch);
   * remat policies offload named activations to the host between forward and backward.
 
-BACKEND GATING (documented in DESIGN.md): the XLA *CPU* backend cannot execute
-``annotate_device_placement`` — memory-space placement inside a compiled computation is
-TPU-only. On CPU (tests + the 512-device dry-run) ``resolve_memory_kind`` degrades
-``pinned_host`` to ``device`` so everything still compiles, while the **OffloadManifest**
-records the intended host residency; the roofline derives the host-DMA term (the paper's
-remote-tier latency, Table III analogue) from the manifest instead of from
-``memory_analysis()``. On TPU the same code paths emit real host placement. Outside-jit
-placement (``emucxl_alloc/migrate``, KV-page demotion between decode steps) uses real
-``pinned_host`` memory on every backend, including CPU.
+Backend gating: the XLA *CPU* backend cannot execute ``annotate_device_placement``,
+so memory-space placement inside a compiled computation runs on an accelerator only.
+On CPU (tests + the 512-device dry-run) ``resolve_memory_kind`` degrades
+``pinned_host`` to ``device`` so everything still compiles, while the
+**OffloadManifest** records the intended host residency; the roofline derives the
+host-DMA term (the paper's remote-tier latency, Table III analogue) from the manifest
+instead of from ``memory_analysis()``. On TPU the same code paths emit real host
+placement. Outside-jit placement (``emucxl_alloc/migrate``, KV-page demotion between
+decode steps) compiles nothing and uses real ``pinned_host`` memory on the CPU too.
 """
 
 from __future__ import annotations

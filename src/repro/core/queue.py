@@ -534,11 +534,8 @@ class OpQueue:
         if plan.kind == "memcpy":
             drec = lib._resolve(plan.buf.address)
             srec = lib._resolve(plan.src.address)
-            sstore, dstore = lib._storage_rec(srec), lib._storage_rec(drec)
-            chunk = sstore.data[: plan.n]
-            if dstore.node != sstore.node:
-                chunk = jax.device_put(chunk, lib._sharding_for(dstore.node))
-            dstore.data = dstore.data.at[: plan.n].set(chunk)
+            lib._write_bytes(lib._storage_rec(drec), 0,
+                             lib._read_bytes(lib._storage_rec(srec), 0, plan.n))
             lib._touch(drec)
             lib._touch(srec)
             return True
@@ -546,13 +543,11 @@ class OpQueue:
         store = lib._storage_rec(rec)
         lib._touch(rec)
         if plan.kind == "read":
-            return np.asarray(store.data[plan.offset : plan.offset + plan.n])
+            return lib._read_bytes(store, plan.offset, plan.n)
         if plan.kind == "write":
-            store.data = store.data.at[plan.offset : plan.offset + plan.n].set(
-                plan.data[: plan.n]
-            )
+            lib._write_bytes(store, plan.offset, plan.data[: plan.n])
             return True
-        store.data = store.data.at[: plan.n].set(np.uint8(plan.value_byte))  # memset
+        lib._write_bytes(store, 0, np.full(plan.n, plan.value_byte, np.uint8))  # memset
         return plan.buf
 
     # ------------------------------------------------------------------ flush

@@ -2,13 +2,9 @@
 # for compute hot-spots the paper itself optimizes with a custom
 # kernel. Leave this package empty if the paper has none.
 
-from jax.experimental.pallas import tpu as _pltpu
+import jax
 
 
-def tpu_compiler_params(**kwargs):
-    """Version-compatible pltpu compiler params (renamed across jax releases:
-    TPUCompilerParams -> CompilerParams)."""
-    cls = getattr(_pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = _pltpu.TPUCompilerParams
-    return cls(**kwargs)
+def interpret_default() -> bool:
+    """Pallas kernels run interpreted on the CPU backend and compiled elsewhere."""
+    return jax.default_backend() == "cpu"

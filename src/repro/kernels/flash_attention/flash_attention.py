@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
 NEG_INF = -1.0e30
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
@@ -152,7 +150,7 @@ def flash_attention_bnh(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((BN, Sp, hd), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -1,23 +1,16 @@
 """jit'd wrapper: model-facing flash attention with GQA head handling.
 
-On CPU the kernel runs in interpret mode (Python execution of the kernel body) —
-set ``REPRO_PALLAS_INTERPRET=0`` only on a real TPU.
+On the CPU backend the kernel runs in interpret mode (Python execution of the
+kernel body); on an accelerator it is compiled.
 """
 
 from __future__ import annotations
 
-import os
-
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_default
 from repro.kernels.flash_attention.flash_attention import flash_attention_bnh
-
-
-def _interpret_default() -> bool:
-    if os.environ.get("REPRO_PALLAS_INTERPRET"):
-        return os.environ["REPRO_PALLAS_INTERPRET"] != "0"
-    return jax.default_backend() != "tpu"
 
 
 def flash_attention(q, k, v, *, window, scale: float, causal: bool = True,
@@ -37,6 +30,6 @@ def flash_attention(q, k, v, *, window, scale: float, causal: bool = True,
     out = flash_attention_bnh(
         qf, kf, vf, window, causal=causal, scale=scale,
         block_q=block_q, block_k=block_k,
-        interpret=_interpret_default() if interpret is None else interpret,
+        interpret=interpret_default() if interpret is None else interpret,
     )
     return jnp.moveaxis(out.reshape(B, N, S, hd), 1, 2)

@@ -15,8 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
 
 def _ssd_kernel(
     A_ref, D_ref,                 # scalar prefetch: (H,) each
@@ -79,7 +77,7 @@ def _ssd_kernel(
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_pallas(x, dt, A, Bm, C, D, state, *, chunk: int = 32, interpret: bool = True):
+def ssd_pallas(x, dt, A, Bm, C, D, state, *, chunk: int = 32, interpret: bool = False):
     """x: (B,T,H,P); dt: (B,T,H); A,D: (H,); Bm,C: (B,T,N); state: (B,H,P,N)."""
     B, T, H, P = x.shape
     N = Bm.shape[-1]
@@ -117,7 +115,7 @@ def ssd_pallas(x, dt, A, Bm, C, D, state, *, chunk: int = 32, interpret: bool = 
             jax.ShapeDtypeStruct((B, H, Tp, P), jnp.float32),
             jax.ShapeDtypeStruct((B, H, P, N), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -2,18 +2,11 @@
 
 from __future__ import annotations
 
-import os
-
 import jax
 
+from repro.kernels import interpret_default
 from repro.kernels.paged_attention.paged_attention import paged_attention as _kernel
 from repro.kernels.paged_attention.ref import paged_attention_ref
-
-
-def _interpret_default() -> bool:
-    if os.environ.get("REPRO_PALLAS_INTERPRET"):
-        return os.environ["REPRO_PALLAS_INTERPRET"] != "0"
-    return jax.default_backend() != "tpu"
 
 
 def paged_attention(q, k_pages, v_pages, block_table, lengths, window, *,
@@ -23,4 +16,4 @@ def paged_attention(q, k_pages, v_pages, block_table, lengths, window, *,
         return paged_attention_ref(q, k_pages, v_pages, block_table, lengths,
                                    window, scale=scale)
     return _kernel(q, k_pages, v_pages, block_table, lengths, window, scale=scale,
-                   interpret=_interpret_default() if interpret is None else interpret)
+                   interpret=interpret_default() if interpret is None else interpret)

@@ -6,13 +6,16 @@ HBM, cold pages are demoted to the host tier by the KV-cache manager
 (serving/kv_manager.py) using the paper's Policy1/Policy2. The kernel consumes the
 HBM-resident pool + a per-sequence block table.
 
-Layout: q (B, K, G, hd) — query heads grouped under their kv head; pages
-(P, page_size, K, hd). Grid = (B, K, max_pages); the page axis is innermost with
+Layout: q (B, N, hd); pages (P, page_size, K, hd). Grid = (B, max_pages): one
+step fetches every kv head of one page, so each block spans the array's two minor
+dims, which Mosaic requires of a block below (8, 128). Query head n belongs to kv
+head n // (N // K); the scores are computed against all page_size*K rows of the
+page and the other heads' rows masked out. The page axis is innermost with
 flash-style running max/normalizer in VMEM scratch. The *index map reads the block
 table from scalar-prefetch SMEM* — a data-dependent gather of pages straight into
 VMEM, which is exactly the TPU-native replacement for the paper's pointer-chasing
-remote reads. Pages past a sequence's length are skipped (@pl.when), so decode cost
-tracks the true context length, not max_pages.
+remote reads. The compute of pages past a sequence's length is skipped (@pl.when);
+their fetch is not.
 """
 
 from __future__ import annotations
@@ -24,8 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
 NEG_INF = -1.0e30
 
 
@@ -33,18 +34,19 @@ def _paged_kernel(
     table_ref,            # scalar prefetch: (B, max_pages) int32
     len_ref,              # scalar prefetch: (B,) int32
     window_ref,           # scalar prefetch: (1,) int32
-    q_ref,                # (1, 1, G, hd)
-    k_ref,                # (1, page_size, 1, hd)  — page selected by index map
+    q_ref,                # (1, N, hd)
+    k_ref,                # (1, page_size, K, hd)  — page selected by index map
     v_ref,
-    o_ref,                # (1, 1, G, hd)
+    o_ref,                # (1, N, hd)
     m_scr, l_scr, acc_scr,
     *,
     page_size: int,
+    kv_heads: int,
     scale: float,
 ):
     b = pl.program_id(0)
-    p = pl.program_id(2)
-    np_ = pl.num_programs(2)
+    p = pl.program_id(1)
+    np_ = pl.num_programs(1)
     length = len_ref[b]
     window = window_ref[0]
 
@@ -61,30 +63,38 @@ def _paged_kernel(
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)                  # (G, hd)
-        k = k_ref[0, :, 0].astype(jnp.float32)               # (page_size, hd)
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        q = q_ref[0].astype(jnp.float32)                     # (N, hd)
+        n, hd = q.shape
+        # Row t*K + h of the flattened page is token t of kv head h.
+        k = k_ref[0].astype(jnp.float32).reshape(page_size * kv_heads, hd)
+        v = v_ref[0].astype(jnp.float32).reshape(page_size * kv_heads, hd)
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale                                            # (G, page_size)
-        pos = page_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = (pos < length) & (q_pos - pos < window)
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST,
+        ) * scale                                            # (N, page_size*K)
+        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        pos = page_start + col // kv_heads
+        # GQA: query head n attends only to kv head n // (N // K).
+        mask = ((col % kv_heads == row // (n // kv_heads))
+                & (pos < length) & (q_pos - pos < window))
         s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        pexp = jnp.exp(s - m_new[:, None])
+        m_prev = m_scr[...]                                  # (N, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        pexp = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(pexp, axis=1)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
-            pexp, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(pexp, axis=1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+            pexp, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST,
         )
         m_scr[...] = m_new
 
     @pl.when(p == np_ - 1)
     def _finish():
         l = jnp.maximum(l_scr[...], 1e-20)
-        o_ref[0, 0] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -99,44 +109,39 @@ def paged_attention(
     window: jax.Array,       # () int32
     *,
     scale: float,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     B, N, hd = q.shape
     P, page_size, K, _ = k_pages.shape
-    G = N // K
     max_pages = block_table.shape[1]
-    qg = q.reshape(B, K, G, hd)
 
-    kernel = functools.partial(_paged_kernel, page_size=page_size, scale=scale)
-    out = pl.pallas_call(
+    kernel = functools.partial(_paged_kernel, page_size=page_size, kv_heads=K,
+                               scale=scale)
+    # Every block spans the full extent of the array's two minor dims (N, hd)
+    # and (K, hd), as Mosaic requires of blocks smaller than 8x128.
+    page_spec = pl.BlockSpec(
+        (1, page_size, K, hd),
+        # data-dependent page fetch: the block table IS the index map
+        lambda b, p, table, lens, win: (table[b, p], 0, 0, 0),
+    )
+    q_spec = pl.BlockSpec((1, N, hd), lambda b, p, *_: (b, 0, 0))
+    return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(B, K, max_pages),
-            in_specs=[
-                pl.BlockSpec((1, 1, G, hd), lambda b, k, p, *_: (b, k, 0, 0)),
-                # data-dependent page fetch: the block table IS the index map
-                pl.BlockSpec(
-                    (1, page_size, 1, hd),
-                    lambda b, k, p, table, lens, win: (table[b, p], 0, k, 0),
-                ),
-                pl.BlockSpec(
-                    (1, page_size, 1, hd),
-                    lambda b, k, p, table, lens, win: (table[b, p], 0, k, 0),
-                ),
-            ],
-            out_specs=pl.BlockSpec((1, 1, G, hd), lambda b, k, p, *_: (b, k, 0, 0)),
+            grid=(B, max_pages),
+            in_specs=[q_spec, page_spec, page_spec],
+            out_specs=q_spec,
             scratch_shapes=[
-                pltpu.VMEM((G,), jnp.float32),
-                pltpu.VMEM((G,), jnp.float32),
-                pltpu.VMEM((G, hd), jnp.float32),
+                pltpu.VMEM((N, 1), jnp.float32),
+                pltpu.VMEM((N, 1), jnp.float32),
+                pltpu.VMEM((N, hd), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, K, G, hd), q.dtype),
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        out_shape=jax.ShapeDtypeStruct((B, N, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(block_table.astype(jnp.int32), lengths.astype(jnp.int32),
-      jnp.asarray(window, jnp.int32).reshape(1), qg, k_pages, v_pages)
-    return out.reshape(B, N, hd)
+      jnp.asarray(window, jnp.int32).reshape(1), q, k_pages, v_pages)

@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
 
 def _wkv6_kernel(
     r_ref, k_ref, v_ref, w_ref,    # (1, 1, c, K/V)
@@ -77,7 +75,7 @@ def _wkv6_kernel(
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def wkv6_pallas(r, k, v, w, u, state, *, chunk: int = 16, interpret: bool = True):
+def wkv6_pallas(r, k, v, w, u, state, *, chunk: int = 16, interpret: bool = False):
     """r,k,w: (B,T,H,K); v: (B,T,H,V); u: (H,K); state: (B,H,K,V)."""
     B, T, H, K = r.shape
     V = v.shape[-1]
@@ -113,7 +111,7 @@ def wkv6_pallas(r, k, v, w, u, state, *, chunk: int = 16, interpret: bool = True
             jax.ShapeDtypeStruct((B, H, K, V), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((K, V), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -7,28 +7,14 @@ both mesh sizes slice from the same 512 emulated devices.
 
 from __future__ import annotations
 
-import inspect
 from typing import Tuple
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5 exposes explicit axis types; older releases have neither the
-    # enum nor the make_mesh kwarg. Fall back to a sentinel and omit the kwarg.
-    from jax.sharding import AxisType
-except ImportError:
-    AxisType = None
-
-_MAKE_MESH_TAKES_AXIS_TYPES = (
-    AxisType is not None
-    and "axis_types" in inspect.signature(jax.make_mesh).parameters
-)
+from jax.sharding import AxisType, Mesh
 
 
-def _axis_type_kwargs(num_axes: int) -> dict:
-    if _MAKE_MESH_TAKES_AXIS_TYPES:
-        return {"axis_types": (AxisType.Auto,) * num_axes}
-    return {}
+def _auto_axes(num_axes: int) -> Tuple[AxisType, ...]:
+    return (AxisType.Auto,) * num_axes
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -45,7 +31,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
             "any jax import"
         )
     return jax.make_mesh(
-        shape, axes, devices=devices[:n], **_axis_type_kwargs(len(axes))
+        shape, axes, devices=devices[:n], axis_types=_auto_axes(len(axes))
     )
 
 
@@ -55,10 +41,10 @@ def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
     for s in shape:
         n *= s
     return jax.make_mesh(
-        shape, axes, devices=jax.devices()[:n], **_axis_type_kwargs(len(axes))
+        shape, axes, devices=jax.devices()[:n], axis_types=_auto_axes(len(axes))
     )
 
 
 def single_device_mesh() -> Mesh:
     return jax.make_mesh((1,), ("data",), devices=jax.devices()[:1],
-                         **_axis_type_kwargs(1))
+                         axis_types=_auto_axes(1))

@@ -9,19 +9,44 @@ dry-run); on a real pod drop the flag and point --mesh at the slice.
 from __future__ import annotations
 
 import argparse
+import functools
 
 import jax
 
 from repro.configs import get_config
+from repro.configs.base import ArchConfig
 from repro.data.loader import PrefetchLoader
 from repro.data.synthetic import SyntheticTokens
 from repro.distributed import axis_rules
 from repro.launch import specs as sp
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.launch.steps import make_train_step
 from repro.models import transformer as tf
 from repro.optim import adamw
 from repro.runtime.train_loop import TrainLoopConfig, run
+
+
+def build_trainer(cfg: ArchConfig, mesh, rules: str, hp: adamw.OptimizerConfig,
+                  opts: tf.ModelOptions, grad_accum: int = 1, seed: int = 0):
+    """(step, params, opt_state) for `cfg` on `mesh`; call under ``axis_rules``.
+
+    Parameters and optimizer state are made inside jit with their shardings as
+    outputs, so each device only ever holds its own shards. The step donates both
+    (a step's inputs and outputs never need to coexist)."""
+    p_sh = sp.param_shardings(cfg, mesh, rules)
+    o_sh = sp.opt_state_shardings(cfg, hp, mesh, rules)
+    params = jax.jit(tf.init_params, static_argnums=1, out_shardings=p_sh)(
+        jax.random.PRNGKey(seed), cfg)
+    opt = jax.jit(functools.partial(adamw.init_state, hp=hp),
+                  out_shardings=o_sh)(params)
+    step = jax.jit(
+        make_train_step(cfg, opts, hp, grad_accum=grad_accum),
+        in_shardings=(p_sh, o_sh, None),
+        out_shardings=(p_sh, o_sh, None),
+        donate_argnums=(0, 1),
+    )
+    return step, params, opt
 
 
 def main() -> None:
@@ -40,6 +65,7 @@ def main() -> None:
     ap.add_argument("--ckpt-every", type=int, default=50)
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -49,17 +75,9 @@ def main() -> None:
                                decay_steps=args.steps)
 
     with mesh, axis_rules(mesh, args.rules):
-        params = tf.init_params(jax.random.PRNGKey(0), cfg)
-        opt = adamw.init_state(params, hp)
-        p_sh = sp.param_shardings(cfg, mesh, args.rules)
-        o_sh = sp.opt_state_shardings(cfg, hp, mesh, args.rules)
-        params = jax.tree.map(jax.device_put, params, p_sh)
-        opt = jax.tree.map(jax.device_put, opt, o_sh)
-        opts = tf.ModelOptions(moe_impl=args.moe_impl)
-        step = jax.jit(
-            make_train_step(cfg, opts, hp, grad_accum=args.grad_accum),
-            in_shardings=(p_sh, o_sh, None),
-            out_shardings=(p_sh, o_sh, None),
+        step, params, opt = build_trainer(
+            cfg, mesh, args.rules, hp, tf.ModelOptions(moe_impl=args.moe_impl),
+            grad_accum=args.grad_accum,
         )
         src = SyntheticTokens(cfg, args.batch, args.seq, seed=0)
         loader = PrefetchLoader(src)

@@ -104,12 +104,6 @@ class SharedPrefixKV:
             self._maps[host] = self.session.attach(self.segment, host)
         return self._maps[host]
 
-    def _page_payload(self, pool: "PagedKVPool", slot: int) -> np.ndarray:
-        return np.concatenate([
-            np.asarray(pool.k_pool[:, slot]).ravel().view(np.uint8),
-            np.asarray(pool.v_pool[:, slot]).ravel().view(np.uint8),
-        ])
-
     def publish(self, pool: "PagedKVPool", seq_id: int,
                 token_ids=None) -> None:
         """Write the prefix pages from `pool`'s hot slots into the segment
@@ -129,7 +123,7 @@ class SharedPrefixKV:
                     f"prefix page {p} of seq {seq_id} is not hot; promote before "
                     f"publishing"
                 )
-            buf.write(self._page_payload(pool, ref.hot_slot),
+            buf.write(pool.hot_page_bytes(ref.hot_slot),
                       offset=p * self.page_bytes)
         # One release fence publishes every page: the upgrades (and, on a
         # re-publish, the back-invalidations to all importers) overlap in a
@@ -328,16 +322,21 @@ class PagedKVPool:
         return shared.num_pages
 
     # ------------------------------------------------------------------ tiering
+    def hot_page_bytes(self, slot: int) -> np.ndarray:
+        """The bytes of hot slot `slot` as one page moves between tiers: every
+        layer's K, then every layer's V."""
+        return np.concatenate([
+            np.asarray(self.k_pool[:, slot]).ravel().view(np.uint8),
+            np.asarray(self.v_pool[:, slot]).ravel().view(np.uint8),
+        ])
+
     def demote(self, seq_id: int, page_idx: int) -> None:
         """Hot -> cold: DMA the page's bytes into a slab chunk on the remote tier."""
         ref = self._refs[(seq_id, page_idx)]
         if ref.hot_slot is None:
             return
         slot = ref.hot_slot
-        payload = np.concatenate([
-            np.asarray(self.k_pool[:, slot]).ravel().view(np.uint8),
-            np.asarray(self.v_pool[:, slot]).ravel().view(np.uint8),
-        ])
+        payload = self.hot_page_bytes(slot)
         ref.cold_ptr = self.slab.alloc(len(payload), ecxl.REMOTE_MEMORY)
         self.slab.write(ref.cold_ptr, payload)
         ref.hot_slot = None

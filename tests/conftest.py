@@ -1,22 +1,17 @@
 """Shared fixtures. NOTE: no XLA_FLAGS here — smoke tests must see ONE device;
 multi-device tests spawn subprocesses that set the flag before importing jax."""
 
-import os
-import sys
-
-try:
-    import hypothesis  # covered by the per-file F401 ignore in pyproject
-except ModuleNotFoundError:
-    sys.path.insert(0, os.path.dirname(__file__))
-    import _hypothesis_stub
-
-    _hypothesis_stub.install()
-
 import jax
 import numpy as np
 import pytest
+from hypothesis import settings
 
 from repro.core import emucxl as ecxl
+
+# A property test's first example includes JAX's first compile, which can take
+# far longer than hypothesis' 200 ms default deadline; later examples do not.
+settings.register_profile("repro", deadline=None)
+settings.load_profile("repro")
 
 
 @pytest.fixture()

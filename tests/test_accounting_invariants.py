@@ -3,9 +3,7 @@
 Random sequences of alloc/free/resize/migrate/migrate_batch — including
 operations that fail on quota/capacity mid-batch — must keep ``stats(node,
 host)`` exactly equal to the sum of live allocation sizes on that (node, host)
-and must never drive the ``SharedPool`` byte counters negative. Runs under
-real hypothesis when installed, else the deterministic seeded stub
-(tests/_hypothesis_stub.py).
+and must never drive the ``SharedPool`` byte counters negative.
 """
 
 import contextlib

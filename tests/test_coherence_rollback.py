@@ -5,7 +5,7 @@ planning fails mid-way (bounds, short payload, pinned-segment migrate, quota)
 must leave directory holders, per-segment stats, write-combining buffers —
 *including their LRU order*, which decides future forced-drain victims — and
 ``coherence_stats()`` byte-identical to the pre-batch snapshot, under random
-op interleavings (hypothesis or the seeded stub), with capacity-bounded
+op interleavings (hypothesis), with capacity-bounded
 buffers whose forced partial drains are themselves journaled, and in
 deterministic twins that pin each failure mode.
 

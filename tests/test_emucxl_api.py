@@ -1,6 +1,7 @@
 """Paper Table II API surface + Fig 3 lifecycle + hypothesis property tests."""
 
 import contextlib
+import types
 
 import numpy as np
 import pytest
@@ -42,6 +43,51 @@ def test_alloc_tiers_and_memory_kind(lib):
             == lib.memory_kind(ecxl.LOCAL_MEMORY))
     assert (lib.allocations()[b].data.sharding.memory_kind
             == lib.memory_kind(ecxl.REMOTE_MEMORY))
+
+
+def test_data_ops_keep_each_tier_in_its_memory_kind(lib):
+    """Writes, memsets, copies and resizes replace an allocation's buffer; the
+    new buffer must stay in its tier's memory kind."""
+    a = lib.alloc(256, ecxl.REMOTE_MEMORY)
+    b = lib.alloc(256, ecxl.LOCAL_MEMORY)
+    lib.write(np.arange(64, dtype=np.uint8), 32, a)
+    lib.memset(b, 0xFF, 128)
+    lib.memcpy(a, b, 64)
+    lib.memcpy(b, a, 16)
+    c = lib.resize(a, 512)
+    for addr, node in ((b, ecxl.LOCAL_MEMORY), (c, ecxl.REMOTE_MEMORY)):
+        assert (lib.allocations()[addr].data.sharding.memory_kind
+                == lib.memory_kind(node))
+    assert np.array_equal(lib.read(c, 0, 80)[60:80], np.r_[
+        np.full(4, 0xFF, np.uint8), np.arange(32, 48, dtype=np.uint8)])
+
+
+class _FakeDevice:
+    def __init__(self, platform, kind, memory_kinds):
+        self.platform, self.device_kind = platform, kind
+        self._kinds = memory_kinds
+
+    def addressable_memories(self):
+        return [types.SimpleNamespace(kind=k) for k in self._kinds]
+
+    def default_memory(self):
+        return types.SimpleNamespace(kind=self._kinds[0])
+
+
+@pytest.mark.parametrize("device, error", [
+    (_FakeDevice("tpu", "TPU v5 lite", ["device"]), "memory kinds"),
+    (_FakeDevice("tpu", "TPU v99", ["device", "pinned_host"]), "HARDWARE_MODELS"),
+])
+def test_init_refuses_an_accelerator_it_cannot_model(device, error):
+    with pytest.raises(EmuCXLError, match=error):
+        EmuCXL().init(device=device)
+
+
+def test_cpu_without_host_memory_collapses_tiers():
+    lib = EmuCXL()
+    lib.init(device=_FakeDevice("cpu", "cpu", ["device"]))
+    assert lib.memory_kind(ecxl.LOCAL_MEMORY) == lib.memory_kind(
+        ecxl.REMOTE_MEMORY) == "device"
 
 
 def test_read_write_roundtrip(lib):
@@ -106,7 +152,7 @@ def test_bounds_checking(lib):
 
 
 # ------------------------------------------------------------------ properties
-@settings(max_examples=30, deadline=None)
+@settings(max_examples=30)
 @given(
     ops=st.lists(
         st.tuples(st.integers(1, 4096), st.integers(0, 1), st.booleans()),
@@ -132,7 +178,7 @@ def test_accounting_invariant(ops):
     lib.exit()
 
 
-@settings(max_examples=20, deadline=None)
+@settings(max_examples=20)
 @given(
     size=st.integers(1, 2048),
     offset_frac=st.floats(0, 1),
@@ -149,7 +195,7 @@ def test_write_read_identity(size, offset_frac, data):
     lib.exit()
 
 
-@settings(max_examples=15, deadline=None)
+@settings(max_examples=15)
 @given(st.lists(st.integers(0, 1), min_size=1, max_size=8),
        st.binary(min_size=1, max_size=128))
 def test_migration_chain_preserves_bytes(nodes, data):

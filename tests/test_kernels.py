@@ -51,15 +51,18 @@ def test_flash_attention_non_causal():
 
 
 # ------------------------------------------------------------------ paged attention
-@pytest.mark.parametrize("B,N,K,hd,page,maxp", [
-    (2, 4, 2, 64, 16, 4),
-    (3, 8, 8, 32, 8, 6),
-    (1, 8, 1, 128, 32, 2),
-])
+@pytest.mark.parametrize("B,N,K,hd,page,maxp,q_dtype", [
+    (2, 4, 2, 64, 16, 4, jnp.float32),
+    (3, 8, 8, 32, 8, 6, jnp.float32),
+    (1, 8, 1, 128, 32, 2, jnp.float32),
+    # DeepSeek-Coder-33B's head layout as the serving engine passes it:
+    # bf16 queries against float32 pages.
+    (2, 56, 8, 128, 16, 4, jnp.bfloat16),
+], ids=["2-4-2-64-16-4", "3-8-8-32-8-6", "1-8-1-128-32-2", "2-56-8-128-16-4-bf16"])
 @pytest.mark.parametrize("window", [1 << 30, 24])
-def test_paged_attention_sweep(B, N, K, hd, page, maxp, window):
+def test_paged_attention_sweep(B, N, K, hd, page, maxp, q_dtype, window):
     P = B * maxp + 2
-    q = _rand((B, N, hd), jnp.float32)
+    q = _rand((B, N, hd), q_dtype)
     kp = _rand((P, page, K, hd), jnp.float32)
     vp = _rand((P, page, K, hd), jnp.float32)
     table = jnp.asarray(
@@ -68,9 +71,12 @@ def test_paged_attention_sweep(B, N, K, hd, page, maxp, window):
     lengths = jnp.asarray(RNG.integers(1, page * maxp, (B,)), jnp.int32)
     out = paged_attention(q, kp, vp, table, lengths, jnp.int32(window),
                           scale=hd ** -0.5, interpret=True)
-    ref = paged_attention_ref(q, kp, vp, table, lengths, jnp.int32(window),
-                              scale=hd ** -0.5)
-    np.testing.assert_allclose(out, ref, atol=2e-5)
+    ref = paged_attention_ref(q.astype(jnp.float32), kp, vp, table, lengths,
+                              jnp.int32(window), scale=hd ** -0.5)
+    assert out.dtype == q_dtype
+    # A bf16 output carries its own rounding, up to 2^-9 relative.
+    tol = dict(atol=2e-5) if q_dtype == jnp.float32 else dict(rtol=2.0 ** -8, atol=1e-5)
+    np.testing.assert_allclose(out.astype(jnp.float32), ref, **tol)
 
 
 # ------------------------------------------------------------------ wkv6

@@ -20,7 +20,7 @@ def fresh_lib(local=1 << 22, remote=1 << 24) -> EmuCXL:
 
 
 # ------------------------------------------------------------------ LRU tier
-@settings(max_examples=30, deadline=None)
+@settings(max_examples=30)
 @given(st.lists(st.integers(0, 20), min_size=1, max_size=100), st.integers(1, 8))
 def test_lru_tier_never_exceeds_capacity(keys, cap):
     tier = LRUTier(cap)
@@ -127,7 +127,7 @@ def test_slab_migration():
     lib.exit()
 
 
-@settings(max_examples=20, deadline=None)
+@settings(max_examples=20)
 @given(st.lists(st.tuples(st.integers(1, 1024), st.booleans()),
                 min_size=1, max_size=60))
 def test_slab_alloc_free_invariants(ops):
@@ -150,7 +150,7 @@ def test_slab_alloc_free_invariants(ops):
 
 
 # ------------------------------------------------------------------ queue (paper §IV-A)
-@settings(max_examples=25, deadline=None)
+@settings(max_examples=25)
 @given(st.lists(st.one_of(st.integers(-1000, 1000), st.none()),
                 min_size=1, max_size=50),
        st.integers(0, 1))

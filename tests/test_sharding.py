@@ -150,3 +150,55 @@ def test_distributed_train_step_8dev():
     assert abs(losses[0] - result["l_dense"]) < 0.05
     # learning under EP + FSDP (noisy MoE smoke config: compare window means)
     assert sum(losses[-5:]) / 5 < sum(losses[:5]) / 5 - 0.1
+
+
+_BUILD_TRAINER_SCRIPT = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import json
+    import jax
+    from repro.configs import get_config
+    from repro.data.synthetic import SyntheticTokens
+    from repro.distributed import axis_rules
+    from repro.launch.mesh import make_mesh
+    from repro.launch.train import build_trainer
+    from repro.models import transformer as tf
+    from repro.optim import adamw
+
+    cfg = get_config("deepseek-coder-33b").reduced()      # float32
+    mesh = make_mesh((2, 2), ("data", "model"))
+    opts = tf.ModelOptions()
+    batch = SyntheticTokens(cfg, batch=8, seq_len=32, seed=0).batch_at(0)
+    with mesh, axis_rules(mesh, "train_fsdp"):
+        step, params, opt = build_trainer(cfg, mesh, "train_fsdp",
+                                          adamw.OptimizerConfig(), opts)
+        leaves = jax.tree.leaves((params, opt))
+        quarters = all(
+            len({s.device for s in x.addressable_shards}) == 4
+            and (x.sharding.is_fully_replicated
+                 or all(4 * s.data.size == x.size for s in x.addressable_shards))
+            for x in leaves)
+        n_sharded = sum(not x.sharding.is_fully_replicated for x in leaves)
+        host = jax.device_get(params)
+        params, opt, m = step(params, opt, batch)
+    one = jax.jit(lambda p, b: tf.loss_fn(p, cfg, b, opts)[0])(host, batch)
+    print(json.dumps({"quarters": quarters, "n_sharded": n_sharded,
+                      "mesh_loss": float(m["loss"]), "one_loss": float(one)}))
+""")
+
+
+def test_build_trainer_shards_state_and_matches_one_device():
+    """Parameters and optimizer state are born sharded in quarters over a 2x2
+    mesh, and step 1's loss equals one device's forward (float32)."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = "src"
+    out = subprocess.run(
+        [sys.executable, "-c", _BUILD_TRAINER_SCRIPT],
+        capture_output=True, text=True, env=env, cwd=os.path.dirname(
+            os.path.dirname(os.path.abspath(__file__))),
+        timeout=300,
+    )
+    assert out.returncode == 0, out.stderr[-3000:]
+    result = json.loads(out.stdout.strip().splitlines()[-1])
+    assert result["quarters"] and result["n_sharded"] > 0
+    assert abs(result["mesh_loss"] - result["one_loss"]) < 1e-5

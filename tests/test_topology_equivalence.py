@@ -9,9 +9,6 @@ the same modeled times for *any* operation sequence. Two sessions replay
 identical random programs — alloc / write / read / migrate batches / fence /
 acquire — and every observable is compared exactly (``==``, not approx: both
 run the identical arithmetic, so the floats must match to the last bit).
-
-Runs under real hypothesis when installed, else the deterministic seeded stub
-(tests/_hypothesis_stub.py).
 """
 
 import numpy as np
@@ -98,7 +95,7 @@ class _Program:
 _OP = st.tuples(st.integers(0, 6), st.integers(0, 7), st.integers(0, 3))
 
 
-@settings(max_examples=15, deadline=None)
+@settings(max_examples=15)
 @given(st.lists(_OP, min_size=1, max_size=12))
 def test_any_op_sequence_is_bit_identical_across_constructions(ops):
     with _legacy_session() as legacy, _topology_session() as topo:

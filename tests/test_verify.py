@@ -13,9 +13,8 @@ The load-bearing gates:
   ``flush(preflight=...)``) leaves directory / WC / detector / stats state
   byte-identical, and a warned flush commits exactly what an unchecked
   flush commits.
-* Property sweep over random batches (real hypothesis when installed,
-  else the seeded stub): replay and verify agree on soundness for
-  arbitrary op soups, and the verifier is deterministic.
+* Property sweep over random batches (hypothesis): replay and verify agree
+  on soundness for arbitrary op soups, and the verifier is deterministic.
 
 Plus the plumbing: ``flush(preflight="raise")`` raises ``PreflightError``
 and fails the batch's tickets, ``coherence_stats()["preflight"]``
@@ -114,7 +113,7 @@ _EV = st.tuples(
 )
 
 
-@settings(max_examples=40, deadline=None)
+@settings(max_examples=40)
 @given(batch=st.lists(_EV, min_size=1, max_size=14),
        wc_capacity=st.one_of(st.none(), st.integers(1, 2)))
 def test_soundness_holds_on_random_batches(batch, wc_capacity):
