@@ -22,6 +22,7 @@ from repro.configs.base import ArchConfig
 from repro.core.api import CXLSession
 from repro.core.policy import PromotionPolicy
 from repro.models import transformer as tf
+from repro.serving import paged_decode
 from repro.serving.kv_manager import PagedKVPool, SharedPrefixKV
 from repro.serving.paged_decode import paged_decode_step
 
@@ -200,4 +201,6 @@ class ServingEngine:
             "preemptions": self.preemptions,
             "remote_bytes": self.pool.session.stats(1),
             "prefix_imports": self.pool.prefix_imports,
+            # process-wide: the decode step's compile cache is shared by engines
+            "decode_traces": paged_decode.TRACES,
         }

@@ -2,13 +2,20 @@
 
 The jit-compiled counterpart of kv_manager: attention-family archs decode against
 (L, slots, page, K, hd) pools + a block table, using the paged_attention Pallas
-kernel per layer (scanned). New-token K/V are written into the owning page slot
-in-place (donated pools), so a decode step is: embed -> scan layers [paged attn +
-mlp/moe] -> unembed, all reading pages the engine has promoted to the hot tier.
+kernel per layer (scanned). A decode step is one compiled program: embed -> scan
+layers [paged attn + mlp/moe] -> unembed, all reading pages the engine has
+promoted to the hot tier. ``cfg`` and ``opts`` are static, so each batch shape is
+traced and compiled once and every later call dispatches the cached executable.
+
+New-token K/V are written into the owning page slot of new pools, which the step
+returns. The input pools are not donated: a caller may still hold them (a wrapper
+around the step may hand its input pools back), and a donated buffer is deleted
+once the step runs.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Tuple
 
 import jax
@@ -21,7 +28,12 @@ from repro.models import moe as moe_lib
 from repro.models import transformer as tf
 from repro.models.layers import apply_rope, rms_norm
 
+# Times paged_decode_step was traced in this process: one per distinct
+# (cfg, opts, argument shapes), since the body runs only while JAX traces.
+TRACES = 0
 
+
+@functools.partial(jax.jit, static_argnums=(1, 7))
 def paged_decode_step(
     params,
     cfg: ArchConfig,
@@ -33,6 +45,8 @@ def paged_decode_step(
     opts: tf.ModelOptions = tf.ModelOptions(),
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Returns (logits (B, V), new_k_pool, new_v_pool). Attention families only."""
+    global TRACES
+    TRACES += 1
     assert cfg.family not in ("ssm", "hybrid"), "paged decode is for attention archs"
     assert not (cfg.moe and cfg.moe_first_dense), "use uniform stacks for paged demo"
     B = inputs.shape[0]

@@ -78,7 +78,7 @@ def test_paged_decode_step_compiles(one_chip, compiled_kernels):
     B, slots, max_pages = 4, 64, 10
     pool = _spec((cfg.num_layers, slots, PAGE_SIZE, cfg.num_kv_heads,
                   cfg.resolved_head_dim), jnp.float32, one_chip)
-    compiled = jax.jit(paged_decode_step, static_argnums=(1, 7)).lower(
+    compiled = paged_decode_step.lower(
         params, cfg, pool, pool, _spec((B, max_pages), jnp.int32, one_chip),
         _spec((B,), jnp.int32, one_chip), _spec((B, 1), jnp.int32, one_chip),
         tf.ModelOptions(moe_impl="dense"),

@@ -41,6 +41,30 @@ def test_paged_decode_matches_dense(model):
         np.testing.assert_allclose(dense_logits, paged_logits, atol=1e-3)
 
 
+def test_decode_step_traces_once_per_batch_size(model):
+    """The engine's decode step is traced once per batch size: more steps at a
+    size reuse the compiled program, a new size traces one more."""
+    cfg, params = model
+    # 11 slots: a pool shape no other test decodes against, so the first step
+    # at each batch size here is a fresh trace.
+    eng = ServingEngine(params, cfg, num_slots=11, page_size=8, max_batch=2,
+                        max_pages_per_seq=2, policy=Policy1())
+
+    def traces_after_steps(batch, steps):
+        rids = [eng.submit([1, 2], max_new_tokens=steps) for _ in range(batch)]
+        before = eng.tier_stats()["decode_traces"]
+        eng.step()
+        first = eng.tier_stats()["decode_traces"] - before
+        for _ in range(steps):
+            eng.step()
+        assert all(eng.requests[r].state == "finished" for r in rids)
+        return first, eng.tier_stats()["decode_traces"] - before
+
+    assert traces_after_steps(1, 4) == (1, 1)
+    assert traces_after_steps(2, 4) == (1, 1)
+    assert traces_after_steps(1, 2) == (0, 0)
+
+
 def test_pool_demote_promote_roundtrip(lib):
     pool = PagedKVPool(2, 4, 8, 2, 16, lib=lib)
     pool.alloc_page(0, 0)
