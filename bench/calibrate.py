@@ -37,7 +37,7 @@ def main() -> int:
     import jax
 
     from bench import run, system, traffic
-    from bench.reference import decoder
+    from bench.reference import ops
 
     devices = jax.devices()
     if devices[0].platform != "tpu":
@@ -46,6 +46,7 @@ def main() -> int:
     system.import_program()
     cell = {w["name"]: w for w in load_benchmark()["workloads"]}[args.workload]
     cfg = load_config(cell["config"])
+    family = run.architecture(cfg)
     mix = traffic.load_mix(cell["traffic"])
     seq_len = -(-(mix["prompt_tokens"]["max"] + mix["output_tokens"]["max"])
                 // cfg["serving"]["page_size"]) * cfg["serving"]["page_size"]
@@ -60,8 +61,8 @@ def main() -> int:
                "peak": res["device"]["memory_peak_bytes"],
                "served_checked": sum(len(t) for _, t in res["sample"])}
         if not args.rates and not args.no_control and res["sample"]:
-            low = decoder.served_rows(cfg, seed, res["sample"], seq_len,
-                                      cfg["check"]["requests"], quant=decoder.fp8)
+            low = family.served_rows(cfg, seed, res["sample"], seq_len,
+                                     cfg["check"]["requests"], quant=ops.fp8)
             row["control"] = run.compare(res["reference_rows"], low,
                                          [r.argmax(axis=-1) for r in low])
         print("CALIBRATE " + json.dumps(row), flush=True)

@@ -2,7 +2,9 @@
 
 Each reader there takes the traced run's context: the benchmark's own spans
 (``bench.system.Probe``) inside the window, its counters, the decode calls'
-context lengths, and the reduced device trace (``bench.trace_reduce``).
+context lengths, the reduced device trace (``bench.trace_reduce``), and the
+configuration's architecture module (``family``), which counts a step's
+operations and bytes.
 """
 
 from __future__ import annotations
@@ -11,7 +13,7 @@ import statistics
 import sys
 from typing import Optional
 
-from bench import flops, trace_reduce
+from bench import trace_reduce
 
 STEP, DECODE = "ServingEngine.step", "paged_decode_step"
 TIER = ("PagedKVPool.demote", "PagedKVPool.promote")
@@ -39,23 +41,25 @@ def decode_mfu(ctx) -> Optional[float]:
     t = sum(s.end - s.start for s in ctx["spans"] if s.name == DECODE)
     if not t or not ctx["decode_contexts"]:
         return None
-    work = sum(flops.decode_step_flops(ctx["cfg"], c) for c in ctx["decode_contexts"])
+    work = sum(ctx["family"].decode_step_flops(ctx["cfg"], c) for c in ctx["decode_contexts"])
     return 100.0 * work / (t * ctx["peaks"]["bf16_flop_per_s"])
 
 
 def kernel_roofline(ctx, op_names) -> Optional[float]:
     """Least time of the kernel's calls (live pages only) over their device time
     in the trace, in percent. Says on standard error which bound holds."""
+    if not ctx["decode_contexts"]:
+        return None
+    f = b = 0
+    for c in ctx["decode_contexts"]:
+        cf, cb = ctx["family"].paged_attention_cost(ctx["cfg"], c, ctx["page_size"])
+        f, b = f + cf, b + cb
     if ctx["trace"] is None or ctx["device"] is None:
         return None
     by_op = trace_reduce.time_by_op(ctx["trace"].ops[ctx["device"]], ctx["trace"].window)
     kernel_ns = sum(t for name, t in by_op.items() if any(k in name for k in op_names))
-    if not kernel_ns or not ctx["decode_contexts"]:
+    if not kernel_ns:
         return None
-    f = b = 0
-    for c in ctx["decode_contexts"]:
-        cf, cb = flops.paged_attention_cost(ctx["cfg"], c, ctx["page_size"])
-        f, b = f + cf, b + cb
     t_flops = f / ctx["peaks"]["bf16_flop_per_s"]
     t_bytes = b / ctx["peaks"]["hbm_byte_per_s"]
     print(f"paged attention: {f} FLOPs, {b} bytes of live pages, device time "

@@ -3,7 +3,9 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 A cell of ``BENCHMARK.json`` names a configuration (``bench/configs/<name>.json``)
-and a traffic mix (``bench/traffic/<name>.json``). The run makes the weights from
+and a traffic mix (``bench/traffic/<name>.json``); the configuration names its
+architecture module (``"reference"``), through which alone the run knows the
+model's family (``ARCH_INTERFACE``). The run makes the weights from
 the seed, builds the program's serving engine, warms up every batch size the
 engine can run, starts the mix's schedule, and measures the window of
 ``--seconds`` once the preroll has loaded the engine; it keeps serving after the
@@ -58,6 +60,36 @@ def load_benchmark() -> dict:
 
 def load_config(name: str) -> dict:
     return json.loads((BENCH / "configs" / f"{name}.json").read_text())
+
+
+# What an architecture module (a configuration's "reference") provides, cfg
+# being the configuration file as run:
+#   global_leaves(cfg), layer_groups(cfg): the weight leaves, as bench/weights.py
+#       reads them: a table of global leaves, and {group: (table, layers)};
+#   arch_config(cfg, get_config): the program's ArchConfig, `get_config` being
+#       the program's lookup of its named architectures;
+#   program_params(cfg, key): the program's weights, made under jit from the
+#       seed's key;
+#   served_rows(cfg, seed, pairs, seq_len, batch, quant): the float32
+#       reference's logit rows at the served positions of each (prompt, served
+#       tokens) pair, in blocks of `batch` padded to `seq_len`, `quant` rounding
+#       the inputs of every matrix product (bench/reference/ops.py);
+#   decode_step_flops(cfg, contexts): model FLOPs of one decode step;
+#   paged_attention_cost(cfg, contexts, page_size): (FLOPs, bytes) of the paged
+#       attention kernel in one decode step.
+ARCH_INTERFACE = ("global_leaves", "layer_groups", "arch_config", "program_params",
+                  "served_rows", "decode_step_flops", "paged_attention_cost")
+
+
+def architecture(cfg: dict):
+    """The configuration's architecture module: the file its ``"reference"``
+    names, relative to the checkout's root, imported by its dotted name."""
+    rel = pathlib.PurePosixPath(cfg["reference"])
+    module = importlib.import_module(".".join(rel.with_suffix("").parts))
+    missing = [n for n in ARCH_INTERFACE if not callable(getattr(module, n, None))]
+    if missing:
+        raise AttributeError(f"{cfg['reference']} lacks {', '.join(missing)}")
+    return module
 
 
 def metric_reader(name: str):
@@ -202,14 +234,14 @@ def compare(reference, program, served) -> Dict[str, float]:
     gap by which a served token's reference logit lies below the reference's
     best. ``max_logprob_gap``: the widest gap between the program's and the
     reference's log-softmax, over every position and token."""
-    from bench.reference import decoder
+    from bench.reference import ops
 
     token = logprob = 0.0
     for ref, prog, toks in zip(reference, program, served):
         if len(prog) != len(toks) or not len(toks):
             return {"max_logit_gap": float("inf"), "max_logprob_gap": float("inf")}
-        token = max(token, float(decoder.token_gaps(ref, toks).max()))
-        logprob = max(logprob, decoder.logprob_gap(ref, prog[:, : ref.shape[-1]]))
+        token = max(token, float(ops.token_gaps(ref, toks).max()))
+        logprob = max(logprob, ops.logprob_gap(ref, prog[:, : ref.shape[-1]]))
     return {"max_logit_gap": token, "max_logprob_gap": logprob}
 
 
@@ -224,9 +256,8 @@ def run_cell(cfg: dict, mix: dict, per_layer: List[dict], seed: int, seconds: fl
     import numpy as np
     from jax import monitoring
 
-    from bench import system, trace_reduce, traffic
+    from bench import system, trace_reduce, traffic, weights
     from bench.peaks import peaks_for
-    from bench.reference import decoder
 
     system.import_program()
     from repro.launch import compile_cache
@@ -246,9 +277,13 @@ def run_cell(cfg: dict, mix: dict, per_layer: List[dict], seed: int, seconds: fl
         and counts.__setitem__("loaded", counts["loaded"] + 1))
 
     serve = cfg["serving"]
-    arch = system.arch_config(cfg)
-    params = system.program_params(cfg, seed)
+    family = architecture(cfg)
+    arch = system.arch_config(cfg, family)
+    params = system.program_params(cfg, seed, family)
     jax.block_until_ready(params)
+    groups = family.layer_groups(cfg)
+    log(f"weights: {weights.served_bytes(family.global_leaves(cfg), groups)} bytes served, "
+        + ", ".join(f"{g}: {n} layers" for g, (_, n) in groups.items()))
     max_pages = -(-(mix["prompt_tokens"]["max"] + mix["output_tokens"]["max"])
                   // serve["page_size"])
     eng, page_bytes = system.build_engine(
@@ -279,12 +314,15 @@ def run_cell(cfg: dict, mix: dict, per_layer: List[dict], seed: int, seconds: fl
     w0 = time.perf_counter()
     setup_s = w0 - process_start
     before = dict(counts)
-    steps0 = driver.steps
+    steps0, calls0 = driver.steps, len(logits.batch_rows)
     with jax.profiler.TraceAnnotation(WINDOW_SPAN):
         driver.run_until(w0 + seconds)
     w1 = time.perf_counter()
     in_window = {k: counts[k] - before[k] for k in counts}
     steps = driver.steps - steps0
+    occupancy = logits.batch_rows[calls0:]     # rows of each decode call in the window
+    batch_mean = statistics.fmean(occupancy) if occupancy else 0.0
+    batch_max = max(occupancy, default=0)
     if trace:
         jax.profiler.stop_trace()
         probe.close()
@@ -302,6 +340,8 @@ def run_cell(cfg: dict, mix: dict, per_layer: List[dict], seed: int, seconds: fl
     if late:
         log(f"generator lateness (submitted after due): median {statistics.median(late)} s, "
             f"max {max(late)} s over {len(late)} requests")
+    log(f"decode calls in the window: {len(occupancy)}, rows per call: mean {batch_mean}, "
+        f"max {batch_max}")
     log(f"compilations inside the window: {in_window['compiled']} "
         f"(programs loaded from the compilation cache there: {in_window['loaded']})")
     log(f"peak_bytes_in_use: {peak}")
@@ -316,7 +356,8 @@ def run_cell(cfg: dict, mix: dict, per_layer: List[dict], seed: int, seconds: fl
         busy = trace_reduce.busy_ns(tr.ops[dev], tr.window) if dev else 0
         window_ns = tr.window[1] - tr.window[0]
         result["device"].update(busy_s=busy / 1e9, window_s=window_ns / 1e9)
-        ctx = dict(cfg=cfg, page_size=serve["page_size"], peaks=peaks_for(device.device_kind),
+        ctx = dict(cfg=cfg, family=family, page_size=serve["page_size"],
+                   peaks=peaks_for(device.device_kind),
                    spans=spans, decode_contexts=probe.decode_contexts, trace=tr, device=dev)
         for m in per_layer:
             value = metric_reader(m["name"])(ctx)
@@ -348,15 +389,15 @@ def run_cell(cfg: dict, mix: dict, per_layer: List[dict], seed: int, seconds: fl
     load = {"due": len(due), "in_flight": len(attempted),
             "finished": sum(1 for r in records if r.done and w0 <= r.done <= w1),
             "waiting": sum(1 for r in due if not r.token_times), "steps": steps,
-            "preemptions": eng.preemptions}
+            "batch_mean": batch_mean, "batch_max": batch_max, "preemptions": eng.preemptions}
     # Free the program's state before the reference takes the chip.
     logits.close()
     del driver, eng, probe, logits
     gc.collect()
     t_ref = time.perf_counter()
     pairs = [(r.prompt, r.tokens) for r in sample]
-    reference = decoder.served_rows(cfg, seed, pairs, seq_len=max_pages * serve["page_size"],
-                                    batch=check["requests"])
+    reference = family.served_rows(cfg, seed, pairs, seq_len=max_pages * serve["page_size"],
+                                   batch=check["requests"])
     numbers = (compare(reference, program_rows, [r.tokens for r in sample]) if sample
                else {"max_logit_gap": float("inf"), "max_logprob_gap": float("inf")})
     served = sum(len(r.tokens) for r in sample)

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import pathlib
 import sys
 import time
@@ -35,43 +36,18 @@ def import_program():
         sys.path.insert(0, str(SRC))
 
 
-def arch_config(cfg: dict):
-    """The program's ArchConfig for the configuration file: the named
-    architecture with every size the file states set from the file."""
+def arch_config(cfg: dict, family):
+    """The program's ArchConfig, as the configuration's architecture module
+    `family` builds it from the program's named architectures."""
     from repro.configs import get_config
 
-    fields = dict(
-        num_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        num_heads=cfg["num_attention_heads"], num_kv_heads=cfg["num_key_value_heads"],
-        head_dim=wts.head_dim(cfg), vocab_size=cfg["vocab_size"],
-        rope_theta=float(cfg["rope_theta"]), qk_norm=bool(cfg.get("qk_norm")),
-        d_ff=cfg["intermediate_size"], param_dtype=cfg["torch_dtype"],
-        dtype=cfg["torch_dtype"], tie_embeddings=bool(cfg["tie_word_embeddings"]),
-    )
-    if cfg.get("num_experts"):
-        fields.update(moe=True, num_experts=cfg["num_experts"],
-                      experts_per_token=cfg["num_experts_per_tok"],
-                      moe_d_ff=cfg["intermediate_size"],
-                      moe_renormalize=bool(cfg["norm_topk_prob"]))
-    return dataclasses.replace(get_config(cfg["program_arch"]), **fields)
+    return family.arch_config(cfg, get_config)
 
 
-def program_params(cfg: dict, seed: int):
+def program_params(cfg: dict, seed: int, family):
     """The weights in the program's layout and served type, made on the device
-    in one jitted call from the seed."""
-
-    @jax.jit
-    def make(key):
-        w = wts.make_served(cfg, key)
-        g, l = w["global"], w["layers"]
-        attn = {n: l[n] for n in ("wq", "wk", "wv", "wo", "q_norm", "k_norm") if n in l}
-        ffn = {n: l[n] for n in ("router", "w_gate", "w_up", "w_down") if n in l}
-        stack = {"ln1": l["ln1"], "ln2": l["ln2"], "attn": attn,
-                 ("moe" if cfg.get("num_experts") else "mlp"): ffn}
-        return {"embed": g["embed"], "stack": stack, "final_norm": g["final_norm"],
-                "unembed": g["unembed"]}
-
-    return make(wts.seed_key(seed))
+    in one jitted call from the seed by the architecture module `family`."""
+    return jax.jit(functools.partial(family.program_params, cfg))(wts.seed_key(seed))
 
 
 def build_engine(arch, params, *, hot_pool_bytes: int, page_size: int,
@@ -99,16 +75,18 @@ class ServedLogits:
     """The logits of every served token, as the program's decode call gave them.
 
     Wraps the engine's ``_decode_batch``, to learn which request each row of a
-    decode call is, and ``paged_decode_step``, whose logits it starts copying
-    to the host at once. ``collect``, called after each engine step, keeps the
-    rows that produced a token: those of requests past their last prompt
-    token."""
+    decode call is, and ``paged_decode_step``, whose first output, the logits,
+    it starts copying to the host at once; the step's other arguments and
+    outputs are passed through unread. ``collect``, called after each engine
+    step, keeps the rows that produced a token: those of requests past their
+    last prompt token. ``batch_rows`` counts the rows of every decode call."""
 
     def __init__(self, eng):
         import repro.serving.engine as engine_mod
 
         self.eng, self._mod = eng, engine_mod
         self.rows: Dict[int, List[np.ndarray]] = {}
+        self.batch_rows: List[int] = []
         self._pending: list = []
         self._batch: list = []
         decode, decode_batch = engine_mod.paged_decode_step, eng._decode_batch
@@ -116,10 +94,11 @@ class ServedLogits:
 
         def watched_batch(batch):
             self._batch = [(r.rid, r.position + 1 >= len(r.prompt)) for r in batch]
+            self.batch_rows.append(len(batch))
             decode_batch(batch)
 
-        def watched_decode(*args):
-            out = decode(*args)
+        def watched_decode(*args, **kwargs):
+            out = decode(*args, **kwargs)
             out[0].copy_to_host_async()
             self._pending.append((out[0], self._batch))
             return out
@@ -149,43 +128,55 @@ class Span:
 
 
 class Probe:
-    """Spans and counters of one traced window, taken around the program's calls."""
+    """Spans and counters of one traced window, taken around the program's calls.
+
+    The decode call is wrapped whatever its arguments; each row's context (its
+    position + 1) is read from the batch the engine's ``_decode_batch`` was
+    given. After a tier move the probe waits for every device array the pool
+    holds."""
 
     def __init__(self, eng):
         import repro.serving.engine as engine_mod
 
         self.eng, self._mod = eng, engine_mod
         self.spans: List[Span] = []
-        self.decode_contexts: List[List[int]] = []   # per decode call: each length + 1
+        self.decode_contexts: List[List[int]] = []   # per decode call: each position + 1
         self._depth = 0
         decode, step, pool = engine_mod.paged_decode_step, eng.step, eng.pool
-        demote, promote = pool.demote, pool.promote
-        self._decode = decode
+        decode_batch, demote, promote = eng._decode_batch, pool.demote, pool.promote
+        self._decode, self._decode_batch = decode, decode_batch
 
-        def traced_decode(params, cfg, k_pool, v_pool, tables, lengths, *rest):
+        def traced_batch(batch):
+            self.decode_contexts.append([r.position + 1 for r in batch])
+            decode_batch(batch)
+
+        def traced_decode(*args, **kwargs):
             with self.span("paged_decode_step"):
-                out = decode(params, cfg, k_pool, v_pool, tables, lengths, *rest)
+                out = decode(*args, **kwargs)
                 jax.block_until_ready(out[0])
-            self.decode_contexts.append((np.asarray(lengths) + 1).tolist())
             return out
 
         def traced_step():
             with self.span("ServingEngine.step"):
                 step()
 
+        def pool_arrays():
+            return [v for v in vars(pool).values() if isinstance(v, jax.Array)]
+
         def traced_demote(seq_id, page_idx):
             with self.span("PagedKVPool.demote"):
                 demote(seq_id, page_idx)
-                jax.block_until_ready(pool.k_pool)
+                jax.block_until_ready(pool_arrays())
 
         def traced_promote(seq_id, page_idx):
             with self.span("PagedKVPool.promote"):
                 slot = promote(seq_id, page_idx)
-                jax.block_until_ready((pool.k_pool, pool.v_pool))
+                jax.block_until_ready(pool_arrays())
             return slot
 
         engine_mod.paged_decode_step = traced_decode
-        eng.step, pool.demote, pool.promote = traced_step, traced_demote, traced_promote
+        eng.step, eng._decode_batch = traced_step, traced_batch
+        pool.demote, pool.promote = traced_demote, traced_promote
 
     @contextlib.contextmanager
     def span(self, name: str):
@@ -200,6 +191,7 @@ class Probe:
 
     def close(self) -> None:
         self._mod.paged_decode_step = self._decode
+        self.eng._decode_batch = self._decode_batch
         del self.eng.step, self.eng.pool.demote, self.eng.pool.promote
 
 

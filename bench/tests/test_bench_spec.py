@@ -1,4 +1,5 @@
-"""Every cell of BENCHMARK.json resolves, by name, to its files."""
+"""Every cell of BENCHMARK.json resolves, by name, to its files, and its
+configuration to an architecture module that exports the interface."""
 
 import json
 import re
@@ -28,6 +29,19 @@ def test_cell_resolves(cell):
     for m in layer:
         assert m["moves"] in {e["name"] for e in e2e}
         assert callable(run.metric_reader(m["name"]))
+
+
+@pytest.mark.parametrize("cell", SPEC["workloads"], ids=lambda w: w["name"])
+def test_cell_architecture_resolves(cell):
+    cfg = run.load_config(cell["config"])
+    assert (ROOT / cfg["reference"]).is_file()
+    family = run.architecture(cfg)
+    assert all(callable(getattr(family, n)) for n in run.ARCH_INTERFACE)
+
+
+def test_architecture_without_the_interface_is_refused():
+    with pytest.raises(AttributeError, match="program_params"):
+        run.architecture({"reference": "bench/reference/ops.py"})
 
 
 @pytest.mark.parametrize("entry", SPEC["configs"], ids=lambda c: c["name"])

@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from bench import traffic
+from bench.tests.tiny import OPEN_MIX
 
 MIXES = sorted(p.stem for p in (pathlib.Path(traffic.__file__).parent / "traffic").glob("*.json"))
 BIG_SEED = 2**31 + 12345
@@ -82,3 +83,16 @@ def test_schedule_has_its_three_parts(mix):
     assert due == sorted(due)
     for lo, hi in ((-m["preroll_s"], 0), (0, 51), (51, 51 + m["drain_s"])):
         assert sum(lo <= t < hi for t in due) == round(m["rate_per_s"] * (hi - lo))
+
+
+def test_bursts_arrive_together():
+    """A burst of k shares its due time; the part keeps round(rate x length)
+    arrivals, the last burst holding what is left, whatever the seed: the
+    tiny mix's 3 s window holds a burst of 8 and one of 4."""
+    def window(seed):
+        due = [a.due_s for a in traffic.schedule(OPEN_MIX, seed, 512, 3.0)
+               if 0 <= a.due_s < 3.0]
+        return sorted(np.unique(due, return_counts=True)[1].tolist()), due[0]
+
+    assert window(BIG_SEED) == window(BIG_SEED + 1) == ([4, 8], 0.0)
+

@@ -6,6 +6,7 @@ TINY_CFG = {
     "num_hidden_layers": 2, "vocab_size": 512, "rms_norm_eps": 1e-06,
     "rope_theta": 10000.0, "rope_scaling": None, "tie_word_embeddings": False,
     "torch_dtype": "bfloat16", "head_dim": 32, "program_arch": "deepseek-coder-33b",
+    "reference": "bench/reference/decoder.py",
     "serving": {"hot_pool_bytes": 64 * 4096, "page_size": 4, "max_batch": 4},
     "check": {"requests": 4, "max_logit_gap": 0.15, "max_logprob_gap": 0.15},
 }
@@ -19,14 +20,22 @@ TINY_MOE_CFG = dict(
     # which moves a row's log-probabilities by up to about a tenth.
     check={"requests": 4, "max_logit_gap": 0.15, "max_logprob_gap": 0.5})
 
+# The dense configuration through an architecture module that only the tests
+# know, added as a file of its own.
+RECORDING_CFG = dict(TINY_CFG, reference="bench/tests/recording_arch.py")
+
+# Requests come in bursts of twice `max_batch`, all due at once, so the engine
+# runs full batches and queues the rest however fast a step is: the window of
+# a 3 s run holds a burst of 8 and one of 4.
 OPEN_MIX = {
-    "rate_per_s": 4.0, "preroll_s": 1.0, "drain_s": 3.0,
+    "rate_per_s": 4.0, "burst": 8, "preroll_s": 1.0, "drain_s": 3.0,
     "prompt_tokens": {"median": 4, "sigma": 0.5, "min": 2, "max": 8},
     "output_tokens": {"median": 4, "sigma": 0.5, "min": 2, "max": 8},
 }
 
-# More live pages than the MoE configuration's 12 hot slots hold: requests
-# are preempted and their pages go through the tier and back.
-SPILL_MIX = dict(OPEN_MIX, rate_per_s=1.5,
+# More live pages than the MoE configuration's 12 hot slots hold: a burst of 8
+# requests of 2 to 4 pages each is preempted, and its pages go through the
+# tier and back.
+SPILL_MIX = dict(OPEN_MIX,
                  prompt_tokens={"median": 6, "sigma": 0.3, "min": 4, "max": 8},
                  output_tokens={"median": 6, "sigma": 0.3, "min": 4, "max": 8})

@@ -1,10 +1,18 @@
 """Seeded random weights, made by the benchmark and not by the program.
 
-Each leaf is drawn from its own key, ``fold_in(seed key, leaf id)``, and layer
-``l`` of a per-layer leaf from ``fold_in(leaf key, l)``. The program's weights
-are made on the device in one jitted call, in the type they are served in; the
-reference makes one layer at a time from the same keys and reads the same
-values in float32 (``layer_weights``).
+An architecture module (the configuration's ``"reference"``) states its leaves
+as tables, ``name -> (shape, kind, fan-in, dtype)`` with kind "matrix",
+"embed" or "norm": one table of global leaves, and one per layer group, a
+group being a table and a layer count. Each leaf is drawn from its own key:
+a global leaf from ``fold_in(seed key, leaf id)``, layer ``l`` of a group's
+leaf from ``fold_in(fold_in(group key, leaf id), l)``. The group key of the
+group named ``layers`` is the seed key itself; any other group's is
+``fold_in(seed key, group id)``, so that leaves named alike in two groups
+differ. An id is the CRC-32 of the name.
+
+The program's weights are made on the device in one jitted call, in the type
+they are served in; the reference makes one layer at a time from the same keys
+and reads the same values in float32 (``layer_weights``).
 
 Values: a matrix is normal with standard deviation 1/sqrt(fan-in); the
 embedding is standard normal; a norm's gain is 1 + s with s normal of standard
@@ -20,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 NORM_SPREAD = 0.1
+DEFAULT_GROUP = "layers"
 
 
 def seed_key(seed: int) -> jax.Array:
@@ -34,55 +43,16 @@ def padded_vocab(cfg: dict) -> int:
     return -(-cfg["vocab_size"] // 256) * 256
 
 
-def head_dim(cfg: dict) -> int:
-    return cfg.get("head_dim") or cfg["hidden_size"] // cfg["num_attention_heads"]
-
-
 def served_dtype(cfg: dict):
     return {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[cfg["torch_dtype"]]
 
 
-# name -> (shape, kind, fan-in, dtype); kind is "matrix", "embed" or "norm".
-def global_leaves(cfg: dict) -> dict:
-    D, V, dt = cfg["hidden_size"], padded_vocab(cfg), served_dtype(cfg)
-    return {
-        "embed": ((V, D), "embed", 1, dt),
-        "final_norm": ((D,), "norm", 1, dt),
-        "unembed": ((D, V), "matrix", D, dt),
-    }
-
-
-def layer_leaves(cfg: dict) -> dict:
-    D, hd, dt = cfg["hidden_size"], head_dim(cfg), served_dtype(cfg)
-    N, K = cfg["num_attention_heads"], cfg["num_key_value_heads"]
-    F = cfg["intermediate_size"]
-    leaves = {
-        "ln1": ((D,), "norm", 1, dt),
-        "wq": ((D, N, hd), "matrix", D, dt),
-        "wk": ((D, K, hd), "matrix", D, dt),
-        "wv": ((D, K, hd), "matrix", D, dt),
-        "wo": ((N, hd, D), "matrix", N * hd, dt),
-        "ln2": ((D,), "norm", 1, dt),
-    }
-    if cfg.get("qk_norm"):
-        leaves["q_norm"] = ((hd,), "norm", 1, dt)
-        leaves["k_norm"] = ((hd,), "norm", 1, dt)
-    if cfg.get("num_experts"):
-        E = cfg["num_experts"]
-        # The program holds its router in float32 whatever the served type.
-        leaves["router"] = ((D, E), "matrix", D, jnp.float32)
-        leaves["w_gate"] = ((E, D, F), "matrix", D, dt)
-        leaves["w_up"] = ((E, D, F), "matrix", D, dt)
-        leaves["w_down"] = ((E, F, D), "matrix", F, dt)
-    else:
-        leaves["w_gate"] = ((D, F), "matrix", D, dt)
-        leaves["w_up"] = ((D, F), "matrix", D, dt)
-        leaves["w_down"] = ((F, D), "matrix", F, dt)
-    return leaves
-
-
 def _leaf_key(key, name: str):
     return jax.random.fold_in(key, zlib.crc32(name.encode()) & 0x7FFFFFFF)
+
+
+def _group_key(key, group: str):
+    return key if group == DEFAULT_GROUP else _leaf_key(key, group)
 
 
 def _draw(key, spec) -> jax.Array:
@@ -104,26 +74,34 @@ def _stacked(key, name: str, spec, num_layers: int) -> jax.Array:
         jnp.arange(num_layers))
 
 
-def make_served(cfg: dict, key) -> dict:
-    """Every leaf, in its served type: {"global": {...}, "layers": {name: (L, ...)}}.
-    Call under jit, so that only the served type is materialized."""
-    L = cfg["num_hidden_layers"]
-    return {
-        "global": {n: _draw(_leaf_key(key, n), s) for n, s in global_leaves(cfg).items()},
-        "layers": {n: _stacked(key, n, s, L) for n, s in layer_leaves(cfg).items()},
-    }
-
-
-def global_weights(cfg: dict, key) -> dict:
-    """The reference's view of the global leaves, float32, norms as gains."""
-    return {n: _as_reference(_draw(_leaf_key(key, n), s), s[1])
-            for n, s in global_leaves(cfg).items()}
-
-
-def layer_weights(cfg: dict, key, layer) -> dict:
-    """The reference's view of one layer, float32, norms as gains."""
-    out = {}
-    for n, s in layer_leaves(cfg).items():
-        x = _draw(jax.random.fold_in(_leaf_key(key, n), layer), s)
-        out[n] = _as_reference(x, s[1])
+def make_served(key, global_table: dict, groups: dict) -> dict:
+    """Every leaf, in its served type: {"global": {...}, <group>: {name: (L, ...)}}
+    for each group of `groups` ({group: (table, layers)}). Call under jit, so
+    that only the served type is materialized."""
+    if "global" in groups:
+        raise ValueError("a layer group may not be called 'global'")
+    out = {"global": {n: _draw(_leaf_key(key, n), s) for n, s in global_table.items()}}
+    for group, (table, num_layers) in groups.items():
+        gk = _group_key(key, group)
+        out[group] = {n: _stacked(gk, n, s, num_layers) for n, s in table.items()}
     return out
+
+
+def served_bytes(global_table: dict, groups: dict) -> int:
+    """Bytes of every leaf in its served type."""
+    def size(table, layers=1):
+        return sum(layers * int(np.prod(s[0])) * jnp.dtype(s[3]).itemsize
+                   for s in table.values())
+    return size(global_table) + sum(size(t, l) for t, l in groups.values())
+
+
+def global_weights(key, table: dict) -> dict:
+    """The reference's view of the global leaves, float32, norms as gains."""
+    return {n: _as_reference(_draw(_leaf_key(key, n), s), s[1]) for n, s in table.items()}
+
+
+def layer_weights(key, group: str, table: dict, layer) -> dict:
+    """The reference's view of one layer of a group, float32, norms as gains."""
+    gk = _group_key(key, group)
+    return {n: _as_reference(_draw(jax.random.fold_in(_leaf_key(gk, n), layer), s), s[1])
+            for n, s in table.items()}
